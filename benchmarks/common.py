@@ -28,7 +28,11 @@ from repro.core import (HSDAG, HSDAGConfig, extract_features, FeatureConfig,
 from repro.core.baselines import (BaselineConfig, PlacetoBaseline,
                                   RNNBaseline, cpu_only, gpu_only,
                                   openvino_auto)
+from repro.compile_cache import use_compile_cache
 from repro.graphs import PAPER_BENCHMARKS
+
+# Every benchmark script imports this module before its first compile.
+use_compile_cache()
 
 EPISODES = int(os.environ.get("REPRO_BENCH_EPISODES", "12"))
 UPDATE_TIMESTEP = int(os.environ.get("REPRO_BENCH_TIMESTEP", "10"))

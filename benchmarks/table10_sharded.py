@@ -1,20 +1,19 @@
 """Table 10 — sharded fleet rollouts + streaming corpora (PR-6).
 
-Two sections, both run in child processes so each row gets its own device
-topology / fresh heap:
+Two sections:
 
-* **throughput** — corpus training placements/s at 1/2/4/8 virtual host
-  devices (``XLA_FLAGS=--xla_force_host_platform_device_count=N``), mesh
-  factorizations 1×1 / 1×2 / 2×2 / 2×4 over the ("graphs", "chains") axes.
+* **throughput** — corpus training placements/s in this process, on the
+  devices it sees: mesh factorizations 1×1 / 1×2 / 2×2 / 2×4 over the
+  ("graphs", "chains") axes for 1/2/4/8 devices, as many as are visible.
   One warmup run amortizes compiles; the measured run is steady-state.
-  NOTE: virtual CPU devices share the host's physical cores — on a
-  single-core container the sharded rows measure partition *overhead*, not
-  speedup; the ≥3× scaling claim needs ≥8 physical cores (the row's
-  ``derived`` field records the physical core count so the context is in
-  the CSV).
-* **memory** — peak Python-heap (tracemalloc) and peak RSS for an eager
-  ``build_corpus`` + full featurization versus a ``StreamingCorpus`` pass
-  (LRU ``cache_graphs=8``), at 24 and 240 synthetic graphs of size ~150.
+  Each row names the platform, device kind and device count it ran on
+  (virtual CPU devices, from
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=N``, share the host's
+  cores and measure partition overhead, not speedup).
+* **memory** — in child processes, each with a fresh heap: peak
+  Python-heap (tracemalloc) and peak RSS for an eager ``build_corpus`` +
+  full featurization versus a ``StreamingCorpus`` pass (LRU
+  ``cache_graphs=8``), at 24 and 240 synthetic graphs of size ~150.
   Eager memory grows with the corpus; streaming stays ~flat (bounded by
   the LRU working set).
 
@@ -28,39 +27,31 @@ import os
 import subprocess
 import sys
 import textwrap
+import time
 
 from common import emit
 
 _MESHES = {1: (1, 1), 2: (1, 2), 4: (2, 2), 8: (2, 4)}
 
-_THROUGHPUT_CHILD = """
-    import os, resource, time
-    import jax
+
+def _throughput(gm: int, bm: int, episodes: int) -> float:
+    """Steady corpus-training placements/s on a gm×bm rollout mesh."""
     from repro.core.costmodel import paper_platform
     from repro.core.hsdag import HSDAGConfig
     from repro.core.train.curriculum import CurriculumTrainer
     from repro.graphs import build_corpus
 
-    gm, bm, episodes = {gm}, {bm}, {episodes}
     cfg = HSDAGConfig(num_devices=2, hidden_channel=32,
                       update_timestep=10, batch_chains=4, max_episodes=1)
     corpus = build_corpus("synthetic:family=mixed:count=8:size=24:seed=0")
-    mesh = None if gm * bm == 1 else (gm, bm)
-
-    def trainer():
-        return CurriculumTrainer(cfg, max_buckets=1, graphs_per_episode=4,
-                                 mesh_shape=mesh)
-
-    tr = trainer()
+    tr = CurriculumTrainer(cfg, max_buckets=1, graphs_per_episode=4,
+                           mesh_shape=None if gm * bm == 1 else (gm, bm))
     tr.train_corpus(corpus, platform=paper_platform())       # compile warmup
     t0 = time.perf_counter()
-    res = tr.train_corpus(corpus, platform=paper_platform(),
-                          episodes=episodes)
+    tr.train_corpus(corpus, platform=paper_platform(), episodes=episodes)
     wall = time.perf_counter() - t0
-    placements = episodes * cfg.update_timestep * 4 * cfg.batch_chains
-    rss_mb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
-    print(f"RESULT,{{placements / wall:.3f}},{{rss_mb:.1f}}")
-"""
+    return episodes * cfg.update_timestep * 4 * cfg.batch_chains / wall
+
 
 _MEMORY_CHILD = """
     import resource, tracemalloc
@@ -85,11 +76,10 @@ _MEMORY_CHILD = """
 """
 
 
-def _run_child(code: str, devices: int = 1) -> list:
+def _run_child(code: str) -> list:
+    """Host-heap measurement in a fresh process; it never needs a device."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={devices}")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "src"),
@@ -107,24 +97,25 @@ def _run_child(code: str, devices: int = 1) -> list:
 
 
 def main() -> None:
-    cores = os.cpu_count() or 1
+    import jax
+    dev = jax.devices()
+    where = (f"platform={dev[0].platform};device_kind={dev[0].device_kind};"
+             f"device_count={len(dev)}")
     episodes = int(os.environ.get("REPRO_BENCH_SHARDED_EPISODES", "2"))
     devices = [int(d) for d in os.environ.get(
-        "REPRO_BENCH_SHARDED_DEVICES", "1,2,4,8").split(",") if d]
+        "REPRO_BENCH_SHARDED_DEVICES", "1,2,4,8").split(",")
+        if d and int(d) <= len(dev)]
 
     base_pps = None
     for n in devices:
         gm, bm = _MESHES[n]
-        pps, rss_mb = _run_child(
-            _THROUGHPUT_CHILD.format(gm=gm, bm=bm, episodes=episodes),
-            devices=n)
-        pps = float(pps)
+        pps = _throughput(gm, bm, episodes)
         if base_pps is None:
             base_pps = pps
         emit(f"table10_sharded_throughput_d{n}_mesh{gm}x{bm}",
              1e6 / max(pps, 1e-9),
              f"placements_per_s={pps:.1f};speedup_vs_d1="
-             f"{pps / base_pps:.2f}x;physical_cores={cores};rss_mb={rss_mb}")
+             f"{pps / base_pps:.2f}x;{where}")
 
     counts = [int(c) for c in os.environ.get(
         "REPRO_BENCH_STREAM_COUNTS", "24,240").split(",") if c]

@@ -5,9 +5,9 @@ Two measurements per graph:
 * ``rollout_throughput_sim_*``   — the reward source alone: host Python
   list-scheduler ``simulate`` vs the batched simulator backends (the
   ``backend=`` field of the derived column compares the jitted+vmapped
-  ``scan`` kernel against the level-parallel ``level`` Pallas kernel; on
-  this CPU container the level kernel runs under interpret=True, so its
-  number is a correctness-mode floor, not the TPU-lowered rate).
+  ``scan`` kernel against the level-major ``level`` Pallas kernel; the
+  row's ``mode=`` says whether the kernel was compiled (TPU) or ran under
+  interpret=True (CPU backend), where its number is no device rate).
 * ``rollout_throughput_search_*`` — the full RL loop (Alg. 1): per-step
   host-reward scalar engine vs the fused B-chain engine with in-jit rewards.
   Steady-state rate (first, compile-bearing episode dropped).
@@ -21,8 +21,8 @@ Rows land in ``BENCH_*.json`` so the scalar→batched speedup is
 regression-checkable.  Env knobs: ``REPRO_BENCH_CHAINS`` (default 16),
 ``REPRO_BENCH_THROUGHPUT_GRAPHS`` (csv; default inception_v3 — the search
 measurement is minutes-per-graph), ``REPRO_BENCH_THROUGHPUT_EPISODES``
-(default 3), ``REPRO_BENCH_LEVEL_BACKEND`` (=0 skips the interpret-mode
-level rows), ``REPRO_BENCH_SWEEP_CHAINS`` (=empty skips the sweep),
+(default 3), ``REPRO_BENCH_LEVEL_BACKEND`` (=0 skips the level rows),
+``REPRO_BENCH_SWEEP_CHAINS`` (=empty skips the sweep),
 ``REPRO_BENCH_SWEEP_TIMESTEP`` / ``REPRO_BENCH_SWEEP_BUDGET``.
 """
 from __future__ import annotations
@@ -40,6 +40,7 @@ from repro.core.costmodel import sim_arrays
 from repro.core.sim import RewardPipeline
 from repro.core.train import make_chain_rngs
 from repro.graphs import PAPER_BENCHMARKS
+from repro.kernels.ops import default_interpret
 
 from common import emit
 
@@ -167,7 +168,8 @@ def main() -> None:
             emit(f"rollout_throughput_sim_{name}_b{CHAINS}_level",
                  1e6 / level,
                  f"evals_per_s={level:.1f};speedup={level / scalar:.2f}x;"
-                 f"backend=level;mode=interpret",
+                 f"backend=level;mode="
+                 f"{'interpret' if default_interpret() else 'compiled'}",
                  config={"graph": name, "backend": "level",
                          "batch_chains": CHAINS})
 

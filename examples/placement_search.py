@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.api import (PlacementSession, PlacementSpec, build_platform,
                        parse_platform_spec)
+from repro.compile_cache import use_compile_cache
 from repro.core import HSDAGConfig, PopulationConfig, simulate
 from repro.core.baselines import cpu_only, gpu_only
 from repro.core.planner import plan_stages
@@ -325,6 +326,7 @@ def main():
         parse_platform_spec(args.platform)
     except ValueError as e:
         ap.error(str(e))
+    use_compile_cache()
     run_spec(args)
 
 

@@ -7,7 +7,7 @@ This cache moves the bound from once-per-process to once-per-build:
 
 * after a :class:`~repro.api.PlacementService` traces a bucket shape, the
   engine's lowered executable is serialized (``jax.export``) and written
-  under ``<dir>/<spec_hash>/greedy_<v>v<e>e<g>g.jaxaot``;
+  under ``<dir>/<spec_hash>/greedy_<format>_<device>_<v>v<e>e<g>g.jaxaot``;
 * a fresh process serving the same ``(spec_hash, bucket shape,
   batch_slots)`` loads the blob and decodes through the deserialized
   executable — **zero traces** (``DynamicRolloutEngine.shape_keys_seen``
@@ -22,6 +22,10 @@ Keying and invalidation:
   operands, so fine-tuning the policy does **not** invalidate its cache.
 * the padded bucket shape ``(v, e)`` and decode width ``g`` pin the operand
   shapes — exactly what the jit cache would key on.
+* ``device`` is the platform and device kind the cache serves
+  (``tpu-TPU-v5-lite``, ``cpu-cpu``): ``jax.export`` lowers for the backend
+  it runs on, so a directory written on one platform is a miss, never a
+  failing decode, on another.
 * blobs embed jax's own export calling-convention version; a jax upgrade
   that cannot replay a blob surfaces as a load failure, which callers
   treat as a miss (re-trace, re-store).  ``clear(spec_hash)`` drops a
@@ -33,12 +37,22 @@ racing on one directory at worst redo an export, never read a torn blob.
 from __future__ import annotations
 
 import os
+import re
 import tempfile
 from typing import Dict, List, Optional, Tuple
+
+import jax
 
 __all__ = ["AotExecutableCache"]
 
 _FORMAT = "v1"  # bump to orphan old blobs if the on-disk layout changes
+
+
+def _device_key() -> str:
+    """``<platform>-<device kind>`` of this process's first device, made
+    safe for a file name (``TPU v5 lite`` → ``TPU-v5-lite``)."""
+    dev = jax.devices()[0]
+    return re.sub(r"[^A-Za-z0-9.]+", "-", f"{dev.platform}-{dev.device_kind}")
 
 
 class AotExecutableCache:
@@ -53,6 +67,9 @@ class AotExecutableCache:
     def __init__(self, directory: str):
         self.directory = str(directory)
         os.makedirs(self.directory, exist_ok=True)
+        #: platform + device kind the blobs are keyed by: this process's
+        #: first device.
+        self.device_key = _device_key()
         self.hits = 0
         self.misses = 0
         self.stores = 0
@@ -62,7 +79,8 @@ class AotExecutableCache:
     def _path(self, spec_hash: str, bucket_shape: Tuple[int, int],
               batch_slots: int) -> str:
         v, e = (int(x) for x in bucket_shape)
-        fname = f"greedy_{_FORMAT}_{v}v{e}e{int(batch_slots)}g.jaxaot"
+        fname = (f"greedy_{_FORMAT}_{self.device_key}_"
+                 f"{v}v{e}e{int(batch_slots)}g.jaxaot")
         return os.path.join(self.directory, str(spec_hash), fname)
 
     # -------------------------------------------------------------- load/store

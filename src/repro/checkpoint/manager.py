@@ -55,18 +55,10 @@ def _jsonable(obj):
     return obj
 
 
-def _keystr_simple(p) -> str:
-    """``jax.tree_util.keystr(..., simple=True)`` for jax 0.4.x too."""
-    for attr in ("key", "idx", "name"):
-        if hasattr(p, attr):
-            return str(getattr(p, attr))
-    return str(p)
-
-
 def _flatten(tree) -> Dict[str, np.ndarray]:
     flat = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        key = "/".join(_keystr_simple(p) for p in path)
+        key = jax.tree_util.keystr(path, simple=True, separator="/")
         arr = np.asarray(leaf)
         if arr.dtype == jnp.bfloat16:
             # npz has no bf16: widen losslessly; restore casts back via the
@@ -80,7 +72,7 @@ def _unflatten(tree_like, flat: Dict[str, np.ndarray]):
     paths = jax.tree_util.tree_flatten_with_path(tree_like)[0]
     leaves = []
     for path, leaf in paths:
-        key = "/".join(_keystr_simple(p) for p in path)
+        key = jax.tree_util.keystr(path, simple=True, separator="/")
         if key not in flat:
             raise KeyError(f"checkpoint missing leaf {key!r}")
         arr = flat[key]

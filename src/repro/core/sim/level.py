@@ -1,10 +1,9 @@
-"""``level`` backend — the level-parallel Pallas makespan kernel.
+"""``level`` backend — the level-major Pallas makespan kernel.
 
-One grid step retires one topological level: readiness (the P-wide
-predecessor segment-max with transfer costs — the heavy phase) is a single
-vectorized (B, W, P) block per level, and only the O(Q) queue bookkeeping
-stays sequential, so the sequential depth of the heavy phase is L (levels)
-instead of V (nodes).  The kernel batches over placements *internally*
+One grid step retires one topological level for a lane-dense tile of
+placements: each node's readiness (the P-wide predecessor max with
+transfer costs) and its device-queue update are full-width row operations
+over the tile.  The kernel batches over placements *internally*
 (the B axis is a kernel dimension, not a ``vmap``), so the backend is
 ``jit_window``: it scores a whole rollout window in one device call rather
 than fusing into the per-sample rollout step.
@@ -16,8 +15,8 @@ Parity is therefore asserted against the reference scheduler *on the same
 order* (``simulate(..., order=prep.arrays.order)``), which this backend's
 tests do for every Table-2 graph and for hypothesis-generated DAGs.
 
-Runs under ``interpret=True`` on CPU (this container, CI) like every other
-kernel; real TPU lowering sits behind ``kernels.ops.default_interpret``.
+Compiles through Mosaic on a TPU; on the CPU backend (tests) the kernel runs
+under ``interpret=True`` — ``kernels.ops.default_interpret`` decides.
 """
 from __future__ import annotations
 

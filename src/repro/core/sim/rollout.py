@@ -27,6 +27,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import export as jax_export
 
 from .pipeline import RewardPipeline
 
@@ -402,16 +403,9 @@ class GraphOperands(NamedTuple):
 
 # AOT export (jax.export) serializes pytree structure by name; registering
 # GraphOperands once here lets any process deserialize an exported decode
-# whose signature carries the operand tuple.  Older jax builds without the
-# hook simply lose AOT support (export_greedy raises), nothing else.
-try:  # pragma: no cover - trivially version-dependent
-    from jax import export as _jax_export
-    _jax_export.register_namedtuple_serialization(
-        GraphOperands, serialized_name="repro.core.sim.GraphOperands")
-    _HAVE_EXPORT = True
-except (ImportError, AttributeError):  # pragma: no cover
-    _jax_export = None
-    _HAVE_EXPORT = False
+# whose signature carries the operand tuple.
+jax_export.register_namedtuple_serialization(
+    GraphOperands, serialized_name="repro.core.sim.GraphOperands")
 
 
 class PopulationWindowFns(NamedTuple):
@@ -907,11 +901,7 @@ class DynamicRolloutEngine:
         of a cold decode).  Parameter *values* are call-time operands, so
         one export survives policy updates; only shape changes invalidate.
         """
-        if not _HAVE_EXPORT:
-            raise RuntimeError(
-                "jax.export is unavailable in this jax build — AOT "
-                "executable caching requires it")
-        return _jax_export.export(self._built[2])(ops, params, keys) \
+        return jax_export.export(self._built[2])(ops, params, keys) \
             .serialize()
 
     def preload_greedy(self, blob: bytes) -> Tuple:
@@ -922,11 +912,11 @@ class DynamicRolloutEngine:
         tracing.  The shape key is recovered from the export's own input
         signature, so the caller needs no side channel.
         """
-        if not _HAVE_EXPORT:
-            raise RuntimeError(
-                "jax.export is unavailable in this jax build — AOT "
-                "executable caching requires it")
-        exported = _jax_export.deserialize(bytes(blob))
+        exported = jax_export.deserialize(bytes(blob))
+        if jax.default_backend() not in exported.platforms:
+            raise ValueError(
+                f"executable was exported for {exported.platforms}; this "
+                f"process runs on {jax.default_backend()!r}")
         args, _ = exported.in_tree.unflatten(list(exported.in_avals))
         key = tuple((tuple(a.shape), str(a.dtype))
                     for a in jax.tree.leaves(args[0]))

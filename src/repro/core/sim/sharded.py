@@ -32,9 +32,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh
 
-from ...distributed.pipeline import shard_map
 from ...distributed.sharding import ROLLOUT_RULES, AxisRules, logical_spec
 from .rollout import GraphOperands, build_window_fns
 

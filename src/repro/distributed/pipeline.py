@@ -22,19 +22,8 @@ from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5: public top-level API, replication check spelled check_vma
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # pinned jax 0.4.x: experimental module, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_SHARD_MAP_CHECK_KW: check_vma})
 
 __all__ = ["pipeline_apply", "make_pipeline_fn"]
 

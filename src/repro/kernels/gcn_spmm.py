@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x spells it TPUCompilerParams; newer jax renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 __all__ = ["gcn_aggregate"]
 
@@ -94,6 +92,6 @@ def gcn_aggregate(adj: jnp.ndarray, h: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((v, f), h.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a32, a32.T, inv[:, None], inv[None, :], h)

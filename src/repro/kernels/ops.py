@@ -1,9 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-TPU is the compile target; on CPU (this container, CI) kernels run in
-``interpret=True`` mode — the kernel body executes in Python on CPU, which
-validates the exact TPU program against the ref.py oracles.  The wrappers
-pick the mode from the actual backend so model code can call one symbol.
+TPU is the compile target.  On the CPU backend (the test suite, with
+``JAX_PLATFORMS=cpu``) kernels run in ``interpret=True`` mode — the kernel
+body executes on the CPU, which validates the TPU program against the
+ref.py oracles.  Any other backend is an error, so a run that lost its chip
+fails instead of interpreting.  The wrappers pick the mode from the actual
+backend so model code can call one symbol.
 """
 from __future__ import annotations
 
@@ -22,7 +24,13 @@ __all__ = ["flash_attention_op", "rmsnorm_op", "gcn_aggregate_op",
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """False on a TPU (compile), True on the CPU backend (interpret)."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for a TPU or interpret on the CPU; "
+            f"the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x spells it TPUCompilerParams; newer jax renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 __all__ = ["ssd_scan"]
 
@@ -68,7 +66,7 @@ def ssd_scan(chunk_decay: jnp.ndarray, dbx: jnp.ndarray, *,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(dec, dbx_t)
     return jnp.moveaxis(before, 2, 1), final

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture × input shape × mesh) cell:
@@ -31,6 +28,7 @@ Usage:
     python -m repro.launch.dryrun --all --out results/dryrun [--jobs 2]
 """
 import argparse
+import os
 import dataclasses
 import json
 import sys
@@ -358,4 +356,7 @@ def _run_all(out_dir: str, jobs: int = 1, skip_unrolled: bool = False) -> int:
 
 
 if __name__ == "__main__":
+    # 512 virtual host devices stand in for the pod meshes; set before
+    # jax initializes its backend, and only when run as a script.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.exit(main())

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """§Perf hillclimbing driver.
 
 Runs a named variant of one (arch × shape) cell — a sharding-rule and/or
@@ -22,6 +19,7 @@ Variants (levers enumerated in EXPERIMENTS.md §Perf):
   sp+moe_g256   combinations via '+'
 """
 import argparse
+import os
 import json
 import sys
 
@@ -90,4 +88,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # 512 virtual host devices stand in for the pod meshes; set before
+    # jax initializes its backend, and only when run as a script.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.exit(main())

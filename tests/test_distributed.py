@@ -119,7 +119,7 @@ def test_compressed_psum_matches_mean():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.distributed.compression import compressed_psum_mean
         n_dev = 8
         mesh = Mesh(np.array(jax.devices()), ("data",))
@@ -131,7 +131,7 @@ def test_compressed_psum_matches_mean():
             return compressed_psum_mean(x, "data", n_dev)
 
         g = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
-                      check_rep=False)
+                      check_vma=False)
         got = np.asarray(g(jnp.asarray(xs)))
         want = xs.mean(0)
         rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)
@@ -145,7 +145,7 @@ def test_compressed_allreduce_error_feedback_converges():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.distributed.compression import compressed_allreduce_tree
         n_dev = 4
         mesh = Mesh(np.array(jax.devices())[:4], ("data",))
@@ -159,7 +159,7 @@ def test_compressed_allreduce_error_feedback_converges():
             return red["w"], new_err.reshape(1, -1)
 
         fn = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                       out_specs=(P(), P("data")), check_rep=False)
+                       out_specs=(P(), P("data")), check_vma=False)
         err = jnp.zeros((4, 2048), jnp.float32)
         # accumulated mean over steps approaches the exact mean (EF property)
         acc = np.zeros(2048, np.float32)
@@ -221,7 +221,7 @@ def test_sharded_matmul_matches_dense():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()), ("model",))
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
         w = jax.random.normal(jax.random.PRNGKey(1), (64, 32))

@@ -271,6 +271,45 @@ def test_aot_corrupt_blob_falls_back_to_trace(fitted_session, tmp_path):
     assert stats["aot_stores"] > 0                   # bad blobs overwritten
 
 
+def test_aot_blob_for_another_device_is_a_miss(fitted_session, tmp_path):
+    """Blobs are keyed by platform and device kind: a directory written
+    under another device's key is a miss here — the service traces and
+    stores its own executables instead of failing requests."""
+    graphs = list(fitted_session.graphs)
+    aot_dir = str(tmp_path / "aot")
+    other = AotExecutableCache(aot_dir)
+    other.device_key = "tpu-TPU-v5-lite"
+    warm = PlacementService(fitted_session, batch_slots=2,
+                            size_granularity=32, aot_cache=other)
+    expected = warm.place_many(graphs)
+    stored = other.stats()["aot_stores"]
+    assert stored > 0
+
+    here = AotExecutableCache(aot_dir)
+    assert here.device_key == "cpu-cpu"
+    fresh = PlacementService(fitted_session, batch_slots=2,
+                             size_granularity=32, aot_cache=here)
+    got = fresh.place_many(graphs)
+    for a, b in zip(expected, got):
+        np.testing.assert_array_equal(a, b)
+    stats = fresh.stats()
+    assert stats["aot_hits"] == 0 and stats["aot_load_failures"] == 0
+    assert stats["aot_misses"] == len(fresh.shape_keys_seen) == stored
+    assert stats["aot_stores"] == stored
+    assert len(here.entries()) == 2 * stored
+
+
+def test_preload_rejects_executable_for_another_platform():
+    import jax
+    import jax.numpy as jnp
+    from jax import export
+    from repro.core.sim.rollout import DynamicRolloutEngine
+    blob = export.export(jax.jit(lambda x: x + 1), platforms=("tpu",))(
+        jax.ShapeDtypeStruct((2,), jnp.float32)).serialize()
+    with pytest.raises(ValueError, match="exported for"):
+        DynamicRolloutEngine(None, HSDAGConfig()).preload_greedy(blob)
+
+
 _FRESH_PROCESS_SCRIPT = textwrap.dedent("""
     import sys, numpy as np
     from repro.api import PlacementService
