@@ -1,0 +1,7 @@
+"""Share of the traced windows in which no operation ran on the device."""
+
+
+def read(ctx):
+    if not ctx["busy_s"]:
+        return None
+    return 100.0 * (1.0 - ctx["busy_s"] / ctx["window_s"])
