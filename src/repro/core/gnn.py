@@ -103,23 +103,29 @@ def encoder_apply(params: Params, x: jnp.ndarray, adj: jnp.ndarray, *,
     them nonzero embeddings); they have no edges, so the GCN layers keep them
     at zero and real nodes never see them.  ``None`` keeps the exact
     single-graph computation.
+
+    Its operations carry the ``repro.encoder`` scope in their ``op_name``.
     """
-    if dropout_rng is not None and edge_dropout > 0.0:
-        keep = jax.random.bernoulli(dropout_rng, 1.0 - edge_dropout, adj.shape)
-        adj = adj * keep.astype(adj.dtype)
-    a_hat = normalize_adjacency(adj)
-    z = mlp_apply(params["trans"], x, act_final=True) if transform else x
-    if node_mask is not None:
-        z = z * node_mask.astype(z.dtype)[:, None]
-    # The layer-param keys identify the model (keeps the pytree string-free).
-    model = "gcn" if (params["gnn"] and "w" in params["gnn"][0]) else "sage"
-    n_layers = len(params["gnn"])
-    for i, layer in enumerate(params["gnn"]):
-        if model == "gcn":
-            z_new = a_hat @ (z @ layer["w"])
-        else:  # sage: mean aggregation over in+out neighbors
-            deg = jnp.clip(adj.sum(0) + adj.sum(1), 1.0)
-            nbr = ((adj + adj.T) @ z) / deg[:, None]
-            z_new = z @ layer["w_self"] + nbr @ layer["w_nbr"]
-        z = jax.nn.relu(z_new) if i < n_layers - 1 else z_new
-    return z
+    with jax.named_scope("repro.encoder"):
+        if dropout_rng is not None and edge_dropout > 0.0:
+            keep = jax.random.bernoulli(dropout_rng, 1.0 - edge_dropout,
+                                        adj.shape)
+            adj = adj * keep.astype(adj.dtype)
+        a_hat = normalize_adjacency(adj)
+        z = mlp_apply(params["trans"], x, act_final=True) if transform else x
+        if node_mask is not None:
+            z = z * node_mask.astype(z.dtype)[:, None]
+        # The layer-param keys identify the model (keeps the pytree
+        # string-free).
+        model = "gcn" if (params["gnn"] and "w" in params["gnn"][0]) \
+            else "sage"
+        n_layers = len(params["gnn"])
+        for i, layer in enumerate(params["gnn"]):
+            if model == "gcn":
+                z_new = a_hat @ (z @ layer["w"])
+            else:  # sage: mean aggregation over in+out neighbors
+                deg = jnp.clip(adj.sum(0) + adj.sum(1), 1.0)
+                nbr = ((adj + adj.T) @ z) / deg[:, None]
+                z_new = z @ layer["w_self"] + nbr @ layer["w_nbr"]
+            z = jax.nn.relu(z_new) if i < n_layers - 1 else z_new
+        return z

@@ -92,6 +92,10 @@ def _connected_components(edges: jnp.ndarray, retained: jnp.ndarray,
     *all* chains converge, and extra ``body`` passes are no-ops at the
     fixpoint (min-propagation is idempotent) — a property the batched
     multi-chain rollout engine relies on.
+
+    The pass before the loop and the loop carry the
+    ``repro.gpn_components`` scope: a profile counts the rounds (the loop's
+    ``it`` + 1) as executions of the loop body.
     """
     src, dst = edges[:, 0], edges[:, 1]
     big = jnp.int32(num_nodes)
@@ -112,8 +116,9 @@ def _connected_components(edges: jnp.ndarray, retained: jnp.ndarray,
         return body(labels), labels, it + 1
 
     init = jnp.arange(num_nodes, dtype=jnp.int32)
-    labels, _, _ = jax.lax.while_loop(
-        cond, step, (body(init), init, jnp.int32(0)))
+    with jax.named_scope("repro.gpn_components"):
+        labels, _, _ = jax.lax.while_loop(
+            cond, step, (body(init), init, jnp.int32(0)))
     return labels
 
 
@@ -188,8 +193,10 @@ def gpn_apply(params: Dict, z: jnp.ndarray, edges: jnp.ndarray,
               dropout_parsing: float = 0.0,
               node_mask: "jnp.ndarray | None" = None,
               edge_mask: "jnp.ndarray | None" = None) -> ParseResult:
-    """Full §2.4 grouping step: scores (Eq. 7) then parse (Eq. 9–11)."""
-    s = edge_scores(params, z, edges, dropout_rng=dropout_rng,
-                    dropout_parsing=dropout_parsing)
-    return parse_graph(s, edges, z, adj, node_mask=node_mask,
-                       edge_mask=edge_mask)
+    """Full §2.4 grouping step: scores (Eq. 7) then parse (Eq. 9–11),
+    under the ``repro.gpn`` scope."""
+    with jax.named_scope("repro.gpn"):
+        s = edge_scores(params, z, edges, dropout_rng=dropout_rng,
+                        dropout_parsing=dropout_parsing)
+        return parse_graph(s, edges, z, adj, node_mask=node_mask,
+                           edge_mask=edge_mask)

@@ -270,10 +270,12 @@ class HSDAG:
         return params
 
     def apply_grads(self, grads: Dict) -> None:
-        """One optimizer step on the shared tree (the Eq.-14 update)."""
-        updates, self._opt_state = self._opt.update(
-            grads, self._opt_state, self.params)
-        self.params = apply_updates(self.params, updates)
+        """One optimizer step on the shared tree (the Eq.-14 update), in the
+        ``repro.optim.update`` host span."""
+        with jax.profiler.TraceAnnotation("repro.optim.update"):
+            updates, self._opt_state = self._opt.update(
+                grads, self._opt_state, self.params)
+            self.params = apply_updates(self.params, updates)
 
     # ------------------------------------------------------------- one round
     def _step(self, params: Dict, z: jnp.ndarray, x0: jnp.ndarray,
@@ -514,6 +516,7 @@ class HSDAG:
                 print(f"ep {episode:3d} reward {h['mean_reward']:.4g} "
                       f"best {best_latency:.6f}s groups {h['mean_groups']:.1f}")
 
+        jax.block_until_ready(self.params)     # the last update is in wall
         wall = time.perf_counter() - t_start
         n_evals = cfg.max_episodes * cfg.update_timestep
         return SearchResult(best_placement, best_latency, history,
@@ -650,6 +653,7 @@ class HSDAG:
                       f"best {best_latency:.6f}s groups {h['mean_groups']:.1f}"
                       f" chains {nchains}")
 
+        jax.block_until_ready(self.params)     # the last update is in wall
         wall = time.perf_counter() - t_start
         n_evals = cfg.max_episodes * tsteps * nchains
         return SearchResult(best_placement, best_latency, history,
@@ -779,6 +783,7 @@ class HSDAG:
             greedy_placements.append(p)
             greedy_latencies[g] = simulate(graphs[g], p, platform).latency
 
+        jax.block_until_ready(self.params)     # the last update is in wall
         wall = time.perf_counter() - t_start
         n_evals = cfg.max_episodes * tsteps * G * nchains
         return MultiSearchResult(

@@ -134,24 +134,29 @@ def policy_apply(params: Dict, pooled_z: jnp.ndarray, active: jnp.ndarray,
     sampled (and replayed) distribution.  Both default to ``None`` — the
     trace-time-dropped branches that keep the dense head's jaxpr
     byte-identical to the pre-knob build.
+
+    Its operations carry the ``repro.head`` scope in their ``op_name``.
     """
-    logits = _head_logits(params, pooled_z, dev_feats)
-    if temperature is not None:
-        logits = logits / temperature
-    if action_mask is not None:
-        ok = _cluster_mask(action_mask, labels, logits.shape[0])
-        logits = jnp.where(ok, logits, logits + _MASK_PENALTY)
-    logp_full = _log_softmax(logits)
-    if greedy:
-        coarse = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        coarse = jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
-    chosen_logp = jnp.take_along_axis(logp_full, coarse[:, None], axis=-1)[:, 0]
-    act = active.astype(logits.dtype)
-    logp = jnp.sum(chosen_logp * act)
-    entropy = jnp.sum(-jnp.sum(jnp.exp(logp_full) * logp_full, -1) * act)
-    fine = coarse[labels]
-    return PolicyOutput(coarse, fine, logp, entropy, logits)
+    with jax.named_scope("repro.head"):
+        logits = _head_logits(params, pooled_z, dev_feats)
+        if temperature is not None:
+            logits = logits / temperature
+        if action_mask is not None:
+            ok = _cluster_mask(action_mask, labels, logits.shape[0])
+            logits = jnp.where(ok, logits, logits + _MASK_PENALTY)
+        logp_full = _log_softmax(logits)
+        if greedy:
+            coarse = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        else:
+            coarse = jax.random.categorical(rng, logits,
+                                            axis=-1).astype(jnp.int32)
+        chosen_logp = jnp.take_along_axis(logp_full, coarse[:, None],
+                                          axis=-1)[:, 0]
+        act = active.astype(logits.dtype)
+        logp = jnp.sum(chosen_logp * act)
+        entropy = jnp.sum(-jnp.sum(jnp.exp(logp_full) * logp_full, -1) * act)
+        fine = coarse[labels]
+        return PolicyOutput(coarse, fine, logp, entropy, logits)
 
 
 def placement_logp(params: Dict, pooled_z: jnp.ndarray, active: jnp.ndarray,

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 from typing import Callable, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from .base import SimulatorBackend, get_backend
@@ -97,8 +98,10 @@ class RewardPipeline:
                             self.prep.arrays)
 
     def step_score(self, sim_tree, placement):
-        """In-jit per-sample hook (fused pipelines only)."""
-        return self.backend.score(sim_tree, placement)
+        """In-jit per-sample hook (fused pipelines only), under the
+        ``repro.sim`` scope whatever the backend."""
+        with jax.named_scope("repro.sim"):
+            return self.backend.score(sim_tree, placement)
 
     # ---------------------------------------------------------------- scoring
     def score_window(self, fines: np.ndarray):

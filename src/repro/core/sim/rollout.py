@@ -80,6 +80,12 @@ class RolloutEngine:
 
     # ----------------------------------------------------- (G, B) window path
     def _build_window_fns(self):
+        # The layer scopes of these programs live only in their metadata,
+        # which JAX leaves out of the persistent compile cache's key by
+        # default: an executable compiled from the same arithmetic under
+        # other names would come back with those names.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         cfg = self._cfg
         step = self._step
         x0, adj, edges = self._x0, self._adj, self._edges
@@ -230,15 +236,21 @@ class RolloutEngine:
             self._window_fns = self._build_window_fns()
         return self._window_fns
 
+    # The host spans name the launch of each window program on the
+    # profiler's clock (the first call's lazy build included); they return
+    # before the device finishes and add no sync.
     def rollout_window(self, params, z, rngs, *, num_steps: int,
                        start_first: bool):
-        return self._window[0](params, z, rngs, num_steps=num_steps,
-                               start_first=start_first)
+        with jax.profiler.TraceAnnotation("repro.rollout"):
+            return self._window[0](params, z, rngs, num_steps=num_steps,
+                                   start_first=start_first)
 
     def window_grads(self, params, z0, keys, weights, *, num_steps: int,
                      start_first: bool):
-        return self._window[1](params, z0, keys, weights,
-                               num_steps=num_steps, start_first=start_first)
+        with jax.profiler.TraceAnnotation("repro.replay"):
+            return self._window[1](params, z0, keys, weights,
+                                   num_steps=num_steps,
+                                   start_first=start_first)
 
     # ------------------------------------------------------- population API
     # The pop path is implemented once, on the operand-style engine; the
