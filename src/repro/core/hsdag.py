@@ -11,11 +11,12 @@ Reward sources (one ``core/sim`` RewardPipeline behind both):
 
 * ``platform=`` (preferred) — rewards come from a registered simulator
   backend: ``engine="scan"`` (default) fuses ``simulate_jax`` *inside* the
-  jitted rollout so a whole ``update_timestep`` window of ``batch_chains``
-  parallel REINFORCE chains runs device-resident with no host↔device sync
-  per step; ``engine="level"`` scores each window in one batched call of the
-  level-parallel Pallas kernel; ``engine="reference"`` scores on the host
-  with the ground-truth Python scheduler.
+  jitted rollout, so a whole ``update_timestep`` window of ``batch_chains``
+  parallel REINFORCE chains runs device-resident with no host↔device sync;
+  the window's placements are scored together after its step scan, in one
+  simulator walk.  ``engine="level"`` scores each window in one batched
+  call of the level-parallel Pallas kernel; ``engine="reference"`` scores
+  on the host with the ground-truth Python scheduler.
 * ``reward_fn(fine_placement) -> (reward, latency)`` — any host callable
   (e.g. ``MeasuredExecutor``, the paper's OpenVINO measurement slot).  The
   rollout is still batched; rewards are filled in on the host per window.

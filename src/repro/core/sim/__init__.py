@@ -16,10 +16,10 @@ backend      scoring     schedule     notes
 reference    host        any order    Python list-scheduler — ground
                                       truth; takes an explicit retire
                                       order for cross-backend parity.
-scan         jit, fused  heap-Kahn    ``simulate_jax`` inlined into the
-             per step    ("topo")     jitted rollout step; bit-for-bit
-                                      the PR-1/PR-2 fused engine and
-                                      the RL default.
+scan         jit, fused  heap-Kahn    ``simulate_jax`` vmapped over a
+             per window  ("topo")     window's placements inside the
+                                      jitted rollout, after its step
+                                      scan; the RL default.
 level        jit, per    level-major  Pallas kernel, one topological
              window      ("level")    level per grid step (segment-max
                                       readiness over the padded pred

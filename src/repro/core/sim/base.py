@@ -14,11 +14,13 @@ through :func:`get_backend`, so adding a backend is::
 
 Two capability flags drive how the RL engine consumes a backend:
 
-* ``jit_fused`` — ``score(prep_tree, placement)`` is jit/vmap-composable and
-  is inlined into the rollout step (rewards computed device-side per sample).
+* ``jit_fused`` — ``score(prep_tree, placement)`` is jit/vmap-composable;
+  the jitted rollout window vmaps it over all of its placements after the
+  step scan (rewards computed device-side, one simulator call per window).
 * ``jit_window`` — scoring is jit-compatible at *window* granularity (one
-  batched device call over every placement a rollout window produced) but not
-  per-step (e.g. a Pallas kernel that batches internally instead of vmapping).
+  batched device call over every placement a rollout window produced) but
+  not as a vmappable per-placement hook (e.g. a Pallas kernel that batches
+  internally instead of vmapping).
 
 Backends with neither flag score on the host (the reference scheduler).
 """
@@ -77,7 +79,7 @@ class SimulatorBackend:
 
     # --------------------------------------------------------------- scoring
     def score(self, prep_tree, placement):
-        """In-jit per-sample hook → (reward, latency) — REQUIRED when
+        """In-jit per-placement hook → (reward, latency) — REQUIRED when
         ``jit_fused``.  ``prep_tree`` is the pytree of dense arrays the
         rollout threads through jit (the engine reads it off the prepared
         handle's ``.arrays`` attribute — fused backends must expose one);

@@ -6,7 +6,7 @@ transfer costs) and its device-queue update are full-width row operations
 over the tile.  The kernel batches over placements *internally*
 (the B axis is a kernel dimension, not a ``vmap``), so the backend is
 ``jit_window``: it scores a whole rollout window in one device call rather
-than fusing into the per-sample rollout step.
+than as a per-placement hook vmapped inside the rollout program.
 
 Order contract: simulates the **level-major** list schedule (see
 ``kernels/levelsim.py``) — a valid topological order, but a different cost

@@ -5,9 +5,10 @@ them into rewards.  Before this layer each reward source had its own wiring
 (`simulate_jax` hardcoded in the fused closures, host ``reward_fn`` loops in
 the drivers).  A pipeline normalizes them to two hooks:
 
-* ``fused`` pipelines expose :meth:`step_score` — inlined into the jitted
-  rollout step, rewards computed device-side per sample (the ``scan``
-  backend; zero host round-trips per window).
+* ``fused`` pipelines expose :meth:`step_score` — the per-placement hook
+  the jitted rollout window vmaps over all of its placements after the
+  step scan, so rewards are computed device-side in one simulator walk per
+  window (the ``scan`` backend; zero host round-trips per window).
 * every pipeline exposes :meth:`score_window` — given the (T, B, V) or
   (T, G, B, V_max) placements a window produced, return (rewards,
   latencies).  ``jit_window`` backends (``level``) run one batched device
@@ -98,8 +99,9 @@ class RewardPipeline:
                             self.prep.arrays)
 
     def step_score(self, sim_tree, placement):
-        """In-jit per-sample hook (fused pipelines only), under the
-        ``repro.sim`` scope whatever the backend."""
+        """In-jit per-placement hook (fused pipelines only), under the
+        ``repro.sim`` scope whatever the backend; the rollout window vmaps
+        it over the window's placements (``rollout.fused_window_scores``)."""
         with jax.named_scope("repro.sim"):
             return self.backend.score(sim_tree, placement)
 

@@ -10,8 +10,10 @@ collapses them:
   window rollout and its differentiable Eq.-14 ``lax.scan`` replay.  The
   single-graph batched search runs the same code at G=1 (proven bitwise
   equal to the former dedicated path by the PR-2 equivalence suite), and
-  rewards come from the :class:`~.pipeline.RewardPipeline` — fused in-jit
-  for the ``scan`` backend, deferred to window scoring otherwise.
+  rewards come from the :class:`~.pipeline.RewardPipeline` — in-jit for
+  the ``scan`` backend (one simulator walk over the whole window's
+  placements, after the step scan: :func:`fused_window_scores`),
+  deferred to window scoring on the host otherwise.
 * :meth:`rollout_step` / :meth:`window_grads_scalar` — the PR-1 scalar
   reference loop (one unbatched chain, Python-unrolled replay), kept
   verbatim as the ground-truth implementation the batched engines are
@@ -39,6 +41,27 @@ def split_multi_keys(rngs: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Per-chain key split over a (G, B, 2) key batch."""
     both = jax.vmap(jax.vmap(jax.random.split))(rngs)    # (G, B, 2, 2)
     return both[:, :, 0], both[:, :, 1]
+
+
+def fused_window_scores(score, sim, fine):
+    """A window's (T, G, B, V) placements → (rewards, latencies), (T, G, B).
+
+    ``score(simg, placement)`` is a fused backend's per-placement hook on
+    one graph's sim tree; ``sim`` carries a leading (G,) axis.  The hook is
+    vmapped over each graph's T·B placements and that over the graphs, so a
+    sequential simulator (the ``scan`` backend's node scan) walks the graph
+    once per window, T·G·B lanes wide.  Scoring never feeds the step scan's
+    carry, so the rollout scores after it rather than once per step.
+    ``score=None`` (a deferred pipeline) gives zeros for the host to fill.
+    """
+    T, G, B, V = fine.shape
+    if score is None:
+        return (jnp.zeros((T, G, B), jnp.float32),) * 2
+    flat = jnp.swapaxes(fine, 0, 1).reshape(G, T * B, V)
+    rewards, latencies = jax.vmap(jax.vmap(score, in_axes=(None, 0)))(
+        sim, flat)
+    return tuple(jnp.swapaxes(a.reshape(G, T, B), 0, 1)
+                 for a in (rewards, latencies))
 
 
 class RolloutEngine:
@@ -104,12 +127,8 @@ class RolloutEngine:
             out = step(params, z, xg, ag, eg, key, first=first, train=True,
                        node_mask=nmg, edge_mask=emg, dev_feats=dvf,
                        action_mask=amask)
-            fine = out.policy.fine_placement
-            if simg is not None:
-                reward, latency = pipeline.step_score(simg, fine)
-            else:
-                reward = latency = jnp.float32(0.0)
-            return (fine, out.parse.num_groups, out.z_next, reward, latency)
+            return (out.policy.fine_placement, out.parse.num_groups,
+                    out.z_next)
 
         def _vsample(params, z, keys, first: bool):
             """z (G, B, V, d), keys (G, B, 2) → per-(g, b) samples."""
@@ -121,7 +140,8 @@ class RolloutEngine:
 
             # Masks and the sim pytree are optional per-graph operands;
             # branch at trace time so absent ones never enter the vmap.
-            if use_masks and fused:
+            # Sampling reads the sim tree only for the capacity mask.
+            if use_masks and mask_sim:
                 return jax.vmap(per_graph)(x0, adj, edges, nmask, emask,
                                            sim, z, keys)
             if use_masks:
@@ -129,7 +149,7 @@ class RolloutEngine:
                     lambda xg, ag, eg, nmg, emg, z_b, k_b: per_graph(
                         xg, ag, eg, nmg, emg, None, z_b, k_b)
                 )(x0, adj, edges, nmask, emask, z, keys)
-            if fused:
+            if mask_sim:
                 return jax.vmap(
                     lambda xg, ag, eg, simg, z_b, k_b: per_graph(
                         xg, ag, eg, None, None, simg, z_b, k_b)
@@ -147,23 +167,23 @@ class RolloutEngine:
             def body(carry, _):
                 z_c, rngs_c = carry
                 rngs_c, keys = split_multi_keys(rngs_c)
-                fine, ngroups, z_next, rew, lat = _vsample(
-                    params, z_c, keys, first=False)
-                return (z_next, rngs_c), (keys, fine, ngroups, rew, lat)
+                fine, ngroups, z_next = _vsample(params, z_c, keys,
+                                                 first=False)
+                return (z_next, rngs_c), (keys, fine, ngroups)
 
             if start_first:
                 rngs, keys0 = split_multi_keys(rngs)
-                fine0, ng0, z, rew0, lat0 = _vsample(params, z, keys0,
-                                                     first=True)
+                fine0, ng0, z = _vsample(params, z, keys0, first=True)
                 (z, rngs), tail = jax.lax.scan(body, (z, rngs), None,
                                                length=num_steps - 1)
-                head = (keys0, fine0, ng0, rew0, lat0)
+                head = (keys0, fine0, ng0)
                 outs = tuple(jnp.concatenate([h[None], t], axis=0)
                              for h, t in zip(head, tail))
             else:
                 (z, rngs), outs = jax.lax.scan(body, (z, rngs), None,
                                                length=num_steps)
-            return (z, rngs) + outs
+            return (z, rngs) + outs + fused_window_scores(
+                pipeline.step_score if fused else None, sim, outs[1])
 
         def _window_loss(params, z0, keys, weights, num_steps: int,
                          start_first: bool):
@@ -493,12 +513,7 @@ def build_window_fns(step, cfg, *, fused: bool, backend, population=None):
         out = step(params, z, xg, ag, eg, key, first=first, train=True,
                    node_mask=nmg, edge_mask=emg, dev_feats=dvg,
                    action_mask=amask)
-        fine = out.policy.fine_placement
-        if simg is not None:
-            reward, latency = backend.score(simg, fine)
-        else:
-            reward = latency = jnp.float32(0.0)
-        return (fine, out.parse.num_groups, out.z_next, reward, latency)
+        return out.policy.fine_placement, out.parse.num_groups, out.z_next
 
     def _vsample(ops, params, z, keys, first: bool):
         def per_graph(xg, ag, eg, nmg, emg, simg, dvg, z_b, k_b):
@@ -506,7 +521,8 @@ def build_window_fns(step, cfg, *, fused: bool, backend, population=None):
                 params, xg, ag, eg, nmg, emg, simg, dvg, z1, k1, first)
             )(z_b, k_b)
 
-        return _graph_vmap(per_graph, ops, (z, keys), with_sim=fused,
+        return _graph_vmap(per_graph, ops, (z, keys),
+                           with_sim=fused and ops.dev_feats is not None,
                            with_dev=ops.dev_feats is not None)
 
     def _rollout_window(ops, params, z, rngs, num_steps: int,
@@ -514,23 +530,23 @@ def build_window_fns(step, cfg, *, fused: bool, backend, population=None):
         def body(carry, _):
             z_c, rngs_c = carry
             rngs_c, keys = split_multi_keys(rngs_c)
-            fine, ngroups, z_next, rew, lat = _vsample(
-                ops, params, z_c, keys, first=False)
-            return (z_next, rngs_c), (keys, fine, ngroups, rew, lat)
+            fine, ngroups, z_next = _vsample(ops, params, z_c, keys,
+                                             first=False)
+            return (z_next, rngs_c), (keys, fine, ngroups)
 
         if start_first:
             rngs, keys0 = split_multi_keys(rngs)
-            fine0, ng0, z, rew0, lat0 = _vsample(ops, params, z, keys0,
-                                                 first=True)
+            fine0, ng0, z = _vsample(ops, params, z, keys0, first=True)
             (z, rngs), tail = jax.lax.scan(body, (z, rngs), None,
                                            length=num_steps - 1)
-            head = (keys0, fine0, ng0, rew0, lat0)
+            head = (keys0, fine0, ng0)
             outs = tuple(jnp.concatenate([h[None], t], axis=0)
                          for h, t in zip(head, tail))
         else:
             (z, rngs), outs = jax.lax.scan(body, (z, rngs), None,
                                            length=num_steps)
-        return (z, rngs) + outs
+        return (z, rngs) + outs + fused_window_scores(
+            backend.score if fused else None, ops.sim, outs[1])
 
     def _window_loss(ops, params, z0, keys, weights, num_steps: int,
                      start_first: bool, denom=None):
@@ -611,12 +627,7 @@ def build_window_fns(step, cfg, *, fused: bool, backend, population=None):
         out = step(params, z, xg, ag, eg, key, first=first, train=True,
                    node_mask=nmg, edge_mask=emg, temperature=temp,
                    dev_feats=dvg, action_mask=amask)
-        fine = out.policy.fine_placement
-        if simg is not None:
-            reward, latency = backend.score(simg, fine)
-        else:
-            reward = latency = jnp.float32(0.0)
-        return (fine, out.parse.num_groups, out.z_next, reward, latency)
+        return out.policy.fine_placement, out.parse.num_groups, out.z_next
 
     def _vsample_pop(ops, params, z, keys, temps, first: bool):
         def per_graph(xg, ag, eg, nmg, emg, simg, dvg, z_b, k_b, t_b):
@@ -624,7 +635,8 @@ def build_window_fns(step, cfg, *, fused: bool, backend, population=None):
                 params, xg, ag, eg, nmg, emg, simg, dvg, z1, k1, t1, first)
             )(z_b, k_b, t_b)
 
-        return _graph_vmap(per_graph, ops, (z, keys, temps), with_sim=fused,
+        return _graph_vmap(per_graph, ops, (z, keys, temps),
+                           with_sim=fused and ops.dev_feats is not None,
                            with_dev=ops.dev_feats is not None)
 
     def _rollout_window_pop(ops, params, z, rngs, pop, num_steps: int,
@@ -637,22 +649,24 @@ def build_window_fns(step, cfg, *, fused: bool, backend, population=None):
         def body(carry, _):
             z_c, rngs_c = carry
             rngs_c, keys = split_multi_keys(rngs_c)
-            fine, ngroups, z_next, rew, lat = _vsample_pop(
-                ops, params, z_c, keys, temps, first=False)
-            return (z_next, rngs_c), (keys, fine, ngroups, rew, lat)
+            fine, ngroups, z_next = _vsample_pop(ops, params, z_c, keys,
+                                                 temps, first=False)
+            return (z_next, rngs_c), (keys, fine, ngroups)
 
         if start_first:
             rngs, keys0 = split_multi_keys(rngs)
-            fine0, ng0, z, rew0, lat0 = _vsample_pop(ops, params, z, keys0,
-                                                     temps, first=True)
+            fine0, ng0, z = _vsample_pop(ops, params, z, keys0, temps,
+                                         first=True)
             (z, rngs), tail = jax.lax.scan(body, (z, rngs), None,
                                            length=num_steps - 1)
-            head = (keys0, fine0, ng0, rew0, lat0)
+            head = (keys0, fine0, ng0)
             outs = tuple(jnp.concatenate([h[None], t], axis=0)
                          for h, t in zip(head, tail))
         else:
             (z, rngs), outs = jax.lax.scan(body, (z, rngs), None,
                                            length=num_steps)
+        outs = outs + fused_window_scores(
+            backend.score if fused else None, ops.sim, outs[1])
         if fused:
             pop = popmod.update_chain_bests(pop, outs[1], outs[4])
         return (z, rngs, pop) + outs

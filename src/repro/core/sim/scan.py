@@ -2,10 +2,11 @@
 
 Retires one node per scan step in the heap-Kahn topo order, reproducing the
 reference scheduler's decisions exactly (≤1e-5 relative, typically ~1e-6 —
-f32 vs f64 rounding only).  The backend is ``jit_fused``: ``score`` is
-inlined into the jitted rollout step, so a whole REINFORCE window of rewards
-is computed device-side with no host round-trips.  This is the default RL
-engine backend and is bit-for-bit the PR-1/PR-2 fused engine.
+f32 vs f64 rounding only).  The backend is ``jit_fused``: the jitted
+rollout window vmaps ``score`` over every placement it sampled, after its
+step scan, so a whole REINFORCE window of rewards is computed device-side in
+one node scan T·G·B lanes wide, with no host round-trips.  This is the
+default RL engine backend.
 """
 from __future__ import annotations
 
