@@ -15,7 +15,8 @@ CACHE = os.path.join(ROOT, ".bench_cache")
 
 
 class BenchError(RuntimeError):
-    """A run that cannot produce a result (no chip, a missing file)."""
+    """A run that cannot produce a result (no chip, a missing file, a
+    configuration whose graph or fleet does not resolve)."""
 
 
 def load_json(kind: str, name: str) -> dict:
@@ -31,6 +32,75 @@ def load_cell(name: str):
     cell = load_json("workloads", name)
     return (cell, load_json("configs", cell["config"]),
             load_json("traffic", cell["traffic"]))
+
+
+def build_graph(config: dict, args: dict | None = None):
+    """The graph a configuration names in ``graph``, by exactly one of
+    ``builder`` (a Table-2 builder of ``repro.graphs.PAPER_BENCHMARKS``,
+    called with ``args``) or ``workload`` (a corpus spec of
+    ``repro.graphs.build_corpus`` that yields one graph).  The graph has to
+    have the ``nodes`` and ``edges`` the entry states.  ``args`` builds a
+    variant of a builder's graph in place of the entry's own ``args``; the
+    entry's counts are not the variant's, so a variant is not counted."""
+    spec = config["graph"]
+    forms = [k for k in ("builder", "workload") if k in spec]
+    if len(forms) != 1:
+        raise BenchError(f"graph {spec!r} names {forms or 'neither'}: give "
+                         f"exactly one of 'builder' and 'workload'")
+    if "builder" in spec:
+        from repro.graphs import PAPER_BENCHMARKS
+        if spec["builder"] not in PAPER_BENCHMARKS:
+            raise BenchError(f"unknown graph builder {spec['builder']!r}; "
+                             f"known: {sorted(PAPER_BENCHMARKS)}")
+        try:
+            graph = PAPER_BENCHMARKS[spec["builder"]](
+                **(spec.get("args", {}) if args is None else args))
+        except TypeError as e:
+            raise BenchError(f"graph builder {spec['builder']!r}: {e}"
+                             ) from None
+    else:
+        if args is not None:
+            raise BenchError(f"graph workload {spec['workload']!r} has no "
+                             f"variants")
+        from repro.graphs import build_corpus
+        try:
+            graphs = build_corpus(spec["workload"], stream=False)
+        except (KeyError, ValueError) as e:
+            raise BenchError(f"graph workload {spec['workload']!r}: {e}"
+                             ) from None
+        if len(graphs) != 1:
+            raise BenchError(f"graph workload {spec['workload']!r} yields "
+                             f"{len(graphs)} graphs; a cell takes one")
+        graph = graphs[0]
+    if args is None:
+        for what, have in (("nodes", graph.num_nodes),
+                           ("edges", graph.num_edges)):
+            if what not in spec:
+                raise BenchError(f"graph {spec!r} states no {what!r}")
+            if have != spec[what]:
+                raise BenchError(f"graph {spec!r} built with {have} {what}, "
+                                 f"not the {spec[what]} it states")
+    return graph
+
+
+def build_platform(config: dict):
+    """The fleet a configuration names in ``platform``, a platform spec
+    (``name:key=value:...``) built by the program's platform registry.  Its
+    device count has to be the policy's ``num_devices``."""
+    from repro.api.spec import (PlacementSpec, build_platform as build,
+                                parse_platform_spec)
+    try:
+        name, args = parse_platform_spec(config["platform"])
+        platform = build(PlacementSpec(workload="", platform=name,
+                                       platform_args=args))
+    except ValueError as e:
+        raise BenchError(f"platform {config['platform']!r}: {e}") from None
+    want = config["policy"]["num_devices"]
+    if platform.num_devices != want:
+        raise BenchError(f"platform {config['platform']!r} has "
+                         f"{platform.num_devices} devices; the policy places "
+                         f"on num_devices={want}")
+    return platform
 
 
 def benchmark_doc() -> dict:

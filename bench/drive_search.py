@@ -27,18 +27,6 @@ import numpy as np
 from . import common
 
 
-def build_graph(spec: dict):
-    from repro.graphs import PAPER_BENCHMARKS
-    return PAPER_BENCHMARKS[spec["builder"]](**spec.get("args", {}))
-
-
-def build_platform(name: str):
-    from repro.core import paper_platform
-    if name != "paper":
-        raise common.BenchError(f"unknown platform {name!r}")
-    return paper_platform()
-
-
 def hsdag_config(config: dict, traffic: dict):
     from repro.core import HSDAGConfig
     return HSDAGConfig(**config["policy"], batch_chains=traffic["chains"],
@@ -63,8 +51,8 @@ class SearchRun:
         from . import ref_policy
 
         self.cell, self.config, self.traffic = cell, config, traffic
-        self.graph = build_graph(config["graph"])
-        self.platform = build_platform(config["platform"])
+        self.graph = common.build_graph(config)
+        self.platform = common.build_platform(config)
         self.arrays = extract_features(
             self.graph, shared_feature_config([self.graph]))
         self.cfg = hsdag_config(config, traffic)

@@ -23,9 +23,10 @@ from . import arrivals, common
 
 
 def build_variants(config: dict, traffic: dict):
-    from repro.graphs import PAPER_BENCHMARKS
-    builder = PAPER_BENCHMARKS[config["graph"]["builder"]]
-    return [builder(layers=layers, seq_len=seq_len)
+    """The configuration's graph at each of the traffic's (layers, seq_len)
+    sizes; the configuration's fleet is checked against its policy."""
+    common.build_platform(config)
+    return [common.build_graph(config, dict(layers=layers, seq_len=seq_len))
             for layers, seq_len in traffic["variants"]]
 
 

@@ -7,8 +7,9 @@ open (the one-chip ``RolloutEngine`` and the mesh's
 ``unchanged``     the update leaves the parameters as they were;
 ``half_batch``    the replay's gradient is the mean over the first half of
                   the chains only;
-``altered``       one node's device is flipped in every placement the
-                  rollout returns, after the simulator scored it;
+``altered``       one node's device is changed (device 0 to 1, any other to
+                  0) in every placement the rollout returns, after the
+                  simulator scored it;
 ``replay_z``      the replay of a window that goes on from carried chains
                   starts them from zeros instead of the carried state;
 ``rollout_z``     the rollout hands on a zeroed chain state instead of the
@@ -85,7 +86,8 @@ def plant(name: str):
             else:
                 fines = out[3]
                 node = fines.shape[-1] // 2
-                out[3] = fines.at[..., node].set(1 - fines[..., node])
+                out[3] = fines.at[..., node].set(
+                    jnp.where(fines[..., node] == 0, 1, 0))
         for cls in _engines():
             def make(orig):
                 def rollout(self, *args, num_steps, start_first):

@@ -10,7 +10,8 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-TINY_GRAPH = {"builder": "bert_base", "args": {"layers": 1, "seq_len": 8}}
+TINY_GRAPH = {"builder": "bert_base", "args": {"layers": 1, "seq_len": 8},
+              "nodes": 94, "edges": 97}
 
 
 def _dump(path, doc):
@@ -20,8 +21,8 @@ def _dump(path, doc):
 
 def tiny_checkout(tmp_path) -> str:
     """Copy the benchmark, link the program, and add tiny cells as data:
-    ``search.tiny``, ``mesh.tiny`` (four virtual CPU devices) and
-    ``serve.tiny``.  No code is edited."""
+    ``search.tiny``, ``search.tiny_fleet``, ``mesh.tiny`` (four virtual CPU
+    devices) and ``serve.tiny``.  No code is edited."""
     root = str(tmp_path / "checkout")
     shutil.copytree(os.path.join(REPO, "bench"), os.path.join(root, "bench"),
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
@@ -32,6 +33,14 @@ def tiny_checkout(tmp_path) -> str:
         cfg = json.load(f)
     cfg.update(name="tiny", graph=TINY_GRAPH)
     _dump(os.path.join(b, "configs", "tiny.json"), cfg)
+    # A graph of a corpus provider on a fleet of the platform registry: one
+    # traced transformer layer (jaxpr primitives) on two NVLink islands of two.
+    cfg = dict(cfg, name="tiny_fleet",
+               graph={"workload": "traced:archs=qwen1.5-0.5b", "nodes": 40,
+                      "edges": 47},
+               platform="nvlink_island:islands=2:gpus_per_island=2",
+               policy=dict(cfg["policy"], num_devices=4))
+    _dump(os.path.join(b, "configs", "tiny_fleet.json"), cfg)
     with open(os.path.join(b, "traffic", "search.json")) as f:
         search = json.load(f)
     search.update(chains=8, window_steps=4, trace_windows=1,
@@ -50,21 +59,25 @@ def tiny_checkout(tmp_path) -> str:
     with open(doc_path) as f:
         doc = json.load(f)
     serve_limits = {"unanswered": 0.0, "logit_gap": 0.0}
-    for kind, traffic, model, chips in (
-            ("search", "search_tiny", "search_chain1.bert_base", 1),
-            ("mesh", "search_mesh_tiny", "search_chain1.bert_base", 4),
-            ("serve", "serve_tiny", "serve.bert_base", 1)):
+    for name, config, traffic, model, chips in (
+            ("search.tiny", "tiny", "search_tiny", "search_chain1.bert_base",
+             1),
+            ("search.tiny_fleet", "tiny_fleet", "search_tiny",
+             "search_chain1.bert_base", 1),
+            ("mesh.tiny", "tiny", "search_mesh_tiny",
+             "search_chain1.bert_base", 4),
+            ("serve.tiny", "tiny", "serve_tiny", "serve.bert_base", 1)):
         with open(os.path.join(b, "workloads", f"{model}.json")) as f:
             cell = json.load(f)
-        cell.update(config="tiny", traffic=traffic, chips=chips)
+        cell.update(config=config, traffic=traffic, chips=chips)
         cell.setdefault("limits", serve_limits)
-        _dump(os.path.join(b, "workloads", f"{kind}.tiny.json"), cell)
-        doc["workloads"].append({"name": f"{kind}.tiny", "config": "tiny",
+        _dump(os.path.join(b, "workloads", f"{name}.json"), cell)
+        doc["workloads"].append({"name": name, "config": config,
                                  "traffic": traffic, "chips": chips,
                                  "why": cell["why"]})
         for m in doc["end_to_end"] + doc["per_layer"]:
             if model in m.get("workloads", []):
-                m["workloads"].append(f"{kind}.tiny")
+                m["workloads"].append(name)
     # The serve cell's metrics, added as data the way a later PR adds them.
     serve = ["serve.tiny"]
     doc["end_to_end"] += [
@@ -80,9 +93,9 @@ def tiny_checkout(tmp_path) -> str:
             ("batch_fill.serve", "%", "higher", "program_counter", "server"),
             ("idle_share.serve", "%", "lower", "device_trace", "device"))
         if n not in {m["name"] for m in doc["per_layer"]}]
-    doc["configs"].append({"name": "tiny", "source": "test",
-                           "file": "bench/configs/tiny.json", "reduced": [],
-                           "why": "test"})
+    doc["configs"] += [{"name": n, "source": "test",
+                        "file": f"bench/configs/{n}.json", "reduced": [],
+                        "why": "test"} for n in ("tiny", "tiny_fleet")]
     _dump(doc_path, doc)
     return root
 
